@@ -102,7 +102,6 @@ def _psum_reducer(mesh, axis_names: tuple, kind: str):
     fn = _COLLECTIVE_CACHE.get(key)
     if fn is not None:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     lead = axis_names if len(axis_names) > 1 else axis_names[0]
@@ -110,16 +109,16 @@ def _psum_reducer(mesh, axis_names: tuple, kind: str):
         def local(leaves):
             return [jax.lax.psum(jnp.sum(l, axis=0), axis_names)
                     for l in leaves]
-        fn = jax.jit(shard_map(local, mesh=mesh, in_specs=(P(lead),),
-                               out_specs=P()))
+        fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(lead),),
+                                   out_specs=P()))
     elif kind == "wsum":
         def local(leaves, w):
             return [jax.lax.psum(jnp.einsum("km,m...->k...", w, l),
                                  axis_names)
                     for l in leaves]
-        fn = jax.jit(shard_map(local, mesh=mesh,
-                               in_specs=(P(lead), P(None, lead)),
-                               out_specs=P()))
+        fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                                   in_specs=(P(lead), P(None, lead)),
+                                   out_specs=P()))
     else:
         raise ValueError(kind)
     _COLLECTIVE_CACHE[key] = fn

@@ -642,50 +642,55 @@ class CohortBackend:
             self._eval_many_jit = jax.jit(self._eval_many_impl)
             self._sig_jit = jax.jit(self._sig_impl)
         else:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec
             c, r = PartitionSpec(clients_axis), PartitionSpec()
 
-            def spmd(fn, in_specs, out_specs, check_rep=True):
+            def spmd(fn, in_specs, out_specs, check_vma):
                 """Cohort SPMD: each device runs ``fn`` on its local client
                 group (and, on a 2-D mesh, its local sample slice).  On the
                 1-D mesh there are no collectives inside — aggregation
                 happens in ``repro.core.aggregate``'s psum programs; the
                 2-D programs psum their sum-form loss/metric terms over the
                 data axis themselves."""
-                return jax.jit(shard_map(fn, mesh=self.mesh,
-                                         in_specs=in_specs,
-                                         out_specs=out_specs,
-                                         check_rep=check_rep))
+                return jax.jit(jax.shard_map(fn, mesh=self.mesh,
+                                             in_specs=in_specs,
+                                             out_specs=out_specs,
+                                             check_vma=check_vma))
 
             # pallas_call has no shard_map replication rule, so the
             # eval/signature programs (the ones that run kernels when the
             # suite's policy is not "reference") must opt out of
-            # rep-checking; training always stays on the XLA path and
-            # keeps the check
+            # varying-manual-axes checking.  The train programs opt out
+            # too: check_vma types every scan carry, and the optimizer's
+            # step counter and the LM loss's chunk accumulators start from
+            # constants that do not vary over `clients`.  Every 1-D
+            # program's outputs are sharded over `clients`, so the check
+            # has no replication claim to verify.
             ck = self.programs.kernel_policy == "reference"
 
             if self._n_data <= 1:
-                self._train_jit = spmd(self._train_impl, (c, c, c, c), (c, c))
+                self._train_jit = spmd(self._train_impl, (c, c, c, c),
+                                       (c, c), check_vma=False)
                 self._train_uniform_jit = spmd(self._train_uniform_impl,
-                                               (c, c, c), (c, c))
+                                               (c, c, c), (c, c),
+                                               check_vma=False)
                 self._eval_jit = spmd(self._eval_impl, (c, c, c, c), c,
-                                      check_rep=ck)
+                                      check_vma=ck)
                 # shared model replicated, K val shards sharded over clients
                 self._eval_shared_jit = spmd(self._eval_shared_impl,
-                                             (r, c, c, c), c, check_rep=ck)
+                                             (r, c, c, c), c, check_vma=ck)
                 # M candidate models sharded, the one val shard replicated
                 self._eval_many_jit = spmd(self._eval_many_impl,
-                                           (c, r, r, r), c, check_rep=ck)
+                                           (c, r, r, r), c, check_vma=ck)
                 self._sig_jit = spmd(self._sig_impl, (c, c, c), c,
-                                     check_rep=ck)
+                                     check_vma=ck)
             else:
                 # 2-D (clients, data): batch arrays split their sample dim
                 # over `data` (dim 2 for train (K, T, B, ...), dim 1 for
                 # eval (K, N, ...)); params replicate within a client group
                 # and the programs psum their sum-form terms over `data`.
-                # check_rep is off: the rep-tracking rules in this jax do
-                # not cover remat/scan composition, and the psum-restored
+                # check_vma is off: the rep-tracking rules do not cover
+                # remat/scan composition, and the psum-restored
                 # replication of params is pinned by the equivalence tests.
                 d = data_axis
                 cb = PartitionSpec(clients_axis, None, d)
@@ -693,21 +698,21 @@ class CohortBackend:
                 dv = PartitionSpec(d)
                 self._train_jit = spmd(self._train2d_impl,
                                        (c, cb, cb, dv, c), (c, c),
-                                       check_rep=False)
+                                       check_vma=False)
                 self._train_uniform_jit = spmd(self._train2d_uniform_impl,
                                                (c, cb, cb, dv), (c, c),
-                                               check_rep=False)
+                                               check_vma=False)
                 self._eval_jit = spmd(self._eval2d_impl, (c, ce, ce, ce), c,
-                                      check_rep=False)
+                                      check_vma=False)
                 self._eval_shared_jit = spmd(self._eval2d_shared_impl,
                                              (r, ce, ce, ce), c,
-                                             check_rep=False)
+                                             check_vma=False)
                 # M models over clients, the ONE shard's samples over data
                 self._eval_many_jit = spmd(self._eval2d_many_impl,
                                            (c, dv, dv, dv), c,
-                                           check_rep=False)
+                                           check_vma=False)
                 self._sig_jit = spmd(self._sig2d_impl, (c, ce, ce), c,
-                                     check_rep=False)
+                                     check_vma=False)
         # host-side window assembly: double-buffered background pipeline
         # (prefetch_window/take) or inline when overlap is off
         from repro.data.pipeline import WindowAssembler

@@ -38,7 +38,7 @@ from repro.kernels.dispatch import (KERNEL_POLICIES, POLICY_ENV,  # noqa: F401
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.mlstm import mlstm_chunkwise_bshd
 from repro.kernels.selective_scan import selective_scan_bsd
-from repro.kernels.signature import signature_td
+from repro.kernels.signature import signature_ntd
 from repro.kernels.slstm import slstm_scan_bsd
 
 
@@ -95,20 +95,41 @@ def signature(x, *, tau: float = 0.05, n_sig: int = 64,
     """
     d = x.shape[-1]
     flat = x.reshape(-1, d)
-    t = flat.shape[0]
-    pad = (-d) % n_sig
-    w = (d + pad) // n_sig
     p = resolve_policy(policy)
     if p == "reference" and interpret is None:
+        t = flat.shape[0]
+        pad = (-d) % n_sig
+        w = (d + pad) // n_sig
         flags = _threshold_flags(flat, tau)              # (T, d)
         if pad:
             flags = jnp.pad(flags, ((0, 0), (0, pad)))
         return jnp.mean(flags.reshape(t, n_sig, w), axis=(0, 2))
-    counts = signature_td(flat, tau=tau, mean=False,
-                          interpret=resolve_interpret(interpret, p))
+    return signature_rows(flat[None], tau=tau, n_sig=n_sig, policy=p,
+                          interpret=interpret)[0]
+
+
+def signature_rows(x, *, tau: float = 0.05, n_sig: int = 64,
+                   policy=None, interpret=None):
+    """Per-row Eq. 3 signatures: (N, ..., d) -> (N, n_sig).
+
+    Bit-identical to ``jax.vmap`` of :func:`signature` over the leading
+    axis, but the kernel path is ONE ``pallas_call`` with the rows as a
+    grid axis (see ``kernels/signature.py``) instead of a vmapped one.
+    """
+    n, d = x.shape[0], x.shape[-1]
+    flat = x.reshape(n, -1, d)
+    p = resolve_policy(policy)
+    if p == "reference" and interpret is None:
+        return jax.vmap(lambda row: signature(
+            row, tau=tau, n_sig=n_sig, policy=p))(flat)
+    t = flat.shape[1]
+    pad = (-d) % n_sig
+    w = (d + pad) // n_sig
+    counts = signature_ntd(flat, tau=tau, mean=False,
+                           interpret=resolve_interpret(interpret, p))
     if pad:
-        counts = jnp.pad(counts, (0, pad))
-    bucket_sums = jnp.sum(counts.reshape(n_sig, w), axis=1)
+        counts = jnp.pad(counts, ((0, 0), (0, pad)))
+    bucket_sums = jnp.sum(counts.reshape(n, n_sig, w), axis=2)
     # multiply-by-reciprocal, NOT division: jnp.mean lowers to
     # sum * (1/n), and the two roundings differ by 1 ulp on ~3% of
     # fraction values — enough to flip tip selections
@@ -132,10 +153,8 @@ def signature_per_channel(x, *, tau: float = 0.0, policy=None,
         return jnp.mean(flags, axis=tuple(range(1, x.ndim - 1)))
     flat = x.reshape(n, -1, c)
     hw = flat.shape[1]
-    it = resolve_interpret(interpret, p)
-    counts = jax.vmap(
-        lambda row: signature_td(row, tau=tau, mean=False, interpret=it))(
-        flat)
+    counts = signature_ntd(flat, tau=tau, mean=False,
+                           interpret=resolve_interpret(interpret, p))
     return counts * (1.0 / np.float32(hw))
 
 

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, get_config, reduced
 from repro.models import transformer as tfm
 from repro.models.attention import cache_seq_axis
-from repro.runtime import Runtime
+from repro.runtime import Runtime, enable_compile_cache
 from repro.train.step import make_serve_decode, make_serve_prefill
 
 
@@ -60,8 +60,9 @@ def make_serving_fns(cfg, runtime: Optional[Runtime] = None):
 def greedy_decode(prefill_fn, decode_fn, cfg, params, batch,
                   new_tokens: int):
     """Prefill ``batch`` then greedy-decode ``new_tokens`` against the KV
-    cache.  Returns {tokens (B, new_tokens) int32, prefill_s, decode_s};
-    both clock reads are synced on the device results."""
+    cache.  Returns {tokens (B, new_tokens) int32, margins (B, new_tokens)
+    f32 — the top-2 logit gap behind each greedy pick, prefill_s,
+    decode_s}; both clock reads are synced on the device results."""
     prompt_len = batch["tokens"].shape[1]
     t0 = time.time()
     last_logits, caches = prefill_fn(params, batch)
@@ -70,16 +71,19 @@ def greedy_decode(prefill_fn, decode_fn, cfg, params, batch,
     t_prefill = time.time() - t0
 
     tok = jnp.argmax(last_logits, -1).astype(jnp.int32)[:, None]
-    generated = [tok]
+    generated, step_logits = [tok], [last_logits]
     t0 = time.time()
     for step in range(new_tokens - 1):
         pos = jnp.int32(prompt_len + step)
         tok, logits, caches = decode_fn(params, tok, caches, pos)
         tok = tok[:, None] if tok.ndim == 1 else tok
         generated.append(tok)
+        step_logits.append(logits)
     jax.block_until_ready(tok)
     t_decode = time.time() - t0
+    top2 = jax.lax.top_k(jnp.stack(step_logits, axis=1), 2)[0]
     return {"tokens": jnp.concatenate(generated, axis=1),
+            "margins": top2[..., 0] - top2[..., 1],
             "prefill_s": t_prefill, "decode_s": t_decode}
 
 
@@ -112,6 +116,7 @@ def main():
     ap.add_argument("--prompt", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = dataclasses.replace(reduced(cfg), compute_dtype="float32")

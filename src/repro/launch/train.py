@@ -17,7 +17,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config, reduced
 from repro.data.pipeline import TokenPipeline
 from repro.models import transformer as tfm
-from repro.runtime import Runtime
+from repro.runtime import Runtime, enable_compile_cache
 from repro.train.checkpoint import save_checkpoint
 from repro.train.step import make_train_step
 
@@ -101,6 +101,7 @@ def main():
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--local-steps", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -358,14 +358,12 @@ def per_sample_signature(h, runtime: Runtime = DEFAULT):
     agree whenever no padding is present.
     h: (B, S, d) activations of the designated layer (the final-norm
     output, matching ``Runtime.want_signature``).  Routed through the
-    kernel dispatch layer; the policy (hence the compiled branch) is
-    resolved once, outside the vmap.
+    kernel dispatch layer: one batched kernel over the B rows.
     """
     from repro.kernels import ops as kops
-    policy = kops.policy_from_runtime(runtime)
-    return jax.vmap(lambda row: kops.signature(
-        row, tau=runtime.signature_tau, n_sig=runtime.signature_dims,
-        policy=policy))(h)
+    return kops.signature_rows(
+        h, tau=runtime.signature_tau, n_sig=runtime.signature_dims,
+        policy=kops.policy_from_runtime(runtime))
 
 
 def forward(params, batch, cfg: ArchConfig, runtime: Runtime = DEFAULT,

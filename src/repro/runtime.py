@@ -1,10 +1,15 @@
-"""Runtime knobs threaded through model apply functions."""
+"""Runtime knobs threaded through model apply functions, and the process
+setup the launchers share (persistent compile cache)."""
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
-
-
 from typing import Any, Optional, Tuple
+
+# <repo>/.jax_cache: a fixed path inside the checkout (listed in .gitignore)
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 
 @dataclass(frozen=True)
@@ -41,3 +46,20 @@ def serve_runtime(kernel_policy: Optional[str] = None) -> Runtime:
     if kernel_policy is None or kernel_policy == "reference":
         return Runtime()
     return Runtime(use_pallas=True, kernel_policy=kernel_policy)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a launcher process and
+    return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, places the cache from outside:
+    JAX reads it itself and nothing is set here.  Otherwise the cache goes
+    to the fixed :data:`COMPILE_CACHE_DIR`, so every run from the same
+    checkout reuses what earlier runs compiled.  Launchers call this once
+    at start-up; tests never do."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR

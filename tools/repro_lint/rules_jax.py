@@ -30,8 +30,7 @@ from tools.repro_lint.engine import (Finding, ModuleContext, Rule, qualname,
                                      register)
 
 _JIT_WRAPPERS = {"jax.jit", "jit", "pjit", "jax.pjit"}
-_SHARD_WRAPPERS = {"shard_map", "jax.shard_map",
-                   "jax.experimental.shard_map.shard_map"}
+_SHARD_WRAPPERS = {"shard_map", "jax.shard_map"}
 _WRAPPERS = _JIT_WRAPPERS | _SHARD_WRAPPERS
 _PARTIALS = {"partial", "functools.partial"}
 
