@@ -484,9 +484,7 @@ def main() -> None:
                   f"versions_served={s.get('distinct_versions_served')} "
                   f"parity={par.get('params_bitwise')}/"
                   f"{par.get('output_parity')} "
-                  f"deterministic={det.get('counters_match')} "
-                  f"[{s.get('queries_per_s', float('nan')):.1f} q/s "
-                  "wall, not gated]")
+                  f"deterministic={det.get('counters_match')}")
         if failures:
             for msg in failures:
                 print(f"PERF GATE FAIL: {msg}", file=sys.stderr)

@@ -49,11 +49,10 @@ from benchmarks.chain_perf import _WORLDS  # noqa: E402
 
 BACKEND_ORDER = ["cnn", "lm"]
 
-#: serving-report keys excluded from the determinism comparison: wall-clock
-#: by definition, and the mean query accuracy (a float average of eval
-#: outputs — the gate pins event counts, never accuracies)
-NONDETERMINISTIC_KEYS = ("query_wall_s", "queries_per_s",
-                         "query_accuracy_mean")
+#: serving-report keys excluded from the determinism comparison: the mean
+#: query accuracy (a float average of eval outputs — the gate pins event
+#: counts, never accuracies)
+NONDETERMINISTIC_KEYS = ("query_accuracy_mean",)
 
 
 def _geometry(quick: bool, backend: str) -> Dict:

@@ -37,6 +37,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.aggregate import (stacked_weighted, tree_mean,
                                   tree_size_bytes, tree_stack, tree_unstack)
 from repro.core.dag import (BoundedDAGLedger, DAGLedger, ModelStore,
@@ -193,6 +194,7 @@ class DagAflCoordinator:
         self._rounds_done = 0
         self._t_last_round = 0.0
         self._cohorts_dispatched = 0
+        self._flushes = 0             # cohort windows flushed (span ids)
         self._val_sets = [client_data[c]["val"] for c in range(cfg.n_clients)]
         self.cohort = None
         self._window: Optional[CohortWindow] = None
@@ -242,8 +244,10 @@ class DagAflCoordinator:
     def _evaluate_tip(self, client: int, tx_id: str) -> float:
         key = (client, tx_id)
         if key not in self._acc_cache:
-            model = self.store.get(self.ledger.get_tx(tx_id).model_ref)
-            acc = self.backend.evaluate(model, self.client_data[client]["val"])
+            with obs.span("dagafl.tip_validate", client=client):
+                model = self.store.get(self.ledger.get_tx(tx_id).model_ref)
+                acc = self.backend.evaluate(model,
+                                            self.client_data[client]["val"])
             self._acc_cache[key] = acc
             self._evals_total += 1
         return self._acc_cache[key]
@@ -254,30 +258,33 @@ class DagAflCoordinator:
         missing = [t for t in tx_ids if (client, t) not in self._acc_cache]
         if not missing:
             return
-        models = [self.store.get(self.ledger.get_tx(t).model_ref)
-                  for t in missing]
-        accs = self.cohort.evaluate_many(models,
-                                         self.client_data[client]["val"])
+        with obs.span("dagafl.tip_validate", client=client):
+            models = [self.store.get(self.ledger.get_tx(t).model_ref)
+                      for t in missing]
+            accs = self.cohort.evaluate_many(models,
+                                             self.client_data[client]["val"])
         for t, acc in zip(missing, accs):
             self._acc_cache[(client, t)] = acc
             self._evals_total += 1
 
     def _publish(self, client: int, model, accuracy: float, sig, epoch: int,
                  parents) -> str:
-        pending = self._deferred_evict.pop(client, None)
-        if pending is not None:         # pruned-while-latest: safe to drop now
-            self._evict_model(pending)
-        ref = self.store.put(f"m{self._refs_issued:012d}", model)
-        self._refs_issued += 1
-        meta = TxMetadata(client_id=client,
-                          signature=tuple(float(s) for s in np.ravel(sig)[:16]),
-                          model_accuracy=float(accuracy),
-                          current_epoch=epoch,
-                          validation_node_id=client)
-        tx = self.ledger.add_transaction(meta, parents, self.loop.now, ref)
-        self.contract.post_signature(client, sig)
-        self.contract.commit_round(epoch)
-        return tx.tx_id
+        with obs.span("dagafl.publish", client=client, epoch=epoch):
+            pending = self._deferred_evict.pop(client, None)
+            if pending is not None:     # pruned-while-latest: safe to drop now
+                self._evict_model(pending)
+            ref = self.store.put(f"m{self._refs_issued:012d}", model)
+            self._refs_issued += 1
+            meta = TxMetadata(
+                client_id=client,
+                signature=tuple(float(s) for s in np.ravel(sig)[:16]),
+                model_accuracy=float(accuracy), current_epoch=epoch,
+                validation_node_id=client)
+            tx = self.ledger.add_transaction(meta, parents, self.loop.now,
+                                             ref)
+            self.contract.post_signature(client, sig)
+            self.contract.commit_round(epoch)
+            return tx.tx_id
 
     def _eval_global_on_vals(self, gm) -> List[float]:
         if self.cohort is not None:
@@ -364,14 +371,16 @@ class DagAflCoordinator:
         """Tip selection + the round's simulated-cost draws, as one record.
         RNG order (seed, then train-time jitter) matches the seed repo's
         sequential stream."""
-        refs, parents, epoch, t_front = self._select_and_cost(client)
-        seed = int(self.rng.integers(2 ** 31))
-        t_train = self.cost.train_time(self.profiles[client],
-                                       self.cfg.local_epochs, self.rng)
-        if self.scenario is not None:
-            # heavy-tailed straggler slowdown (x1.0 exactly for non-
-            # stragglers, so the honest trajectory keeps its bits)
-            t_train *= self.scenario.duration_multiplier(client)
+        with obs.span("dagafl.front_half", client=client,
+                      epoch=self._client_rounds[client] + 1):
+            refs, parents, epoch, t_front = self._select_and_cost(client)
+            seed = int(self.rng.integers(2 ** 31))
+            t_train = self.cost.train_time(self.profiles[client],
+                                           self.cfg.local_epochs, self.rng)
+            if self.scenario is not None:
+                # heavy-tailed straggler slowdown (x1.0 exactly for non-
+                # stragglers, so the honest trajectory keeps its bits)
+                t_train *= self.scenario.duration_multiplier(client)
         return {"client": client, "t_start": t_start, "refs": refs,
                 "parents": parents, "epoch": epoch, "t_front": t_front,
                 "t_train": t_train, "seed": seed}
@@ -382,7 +391,8 @@ class DagAflCoordinator:
         round's own simulated completion time.  Used verbatim by the
         sequential path and by cohort windows of one."""
         client = rd["client"]
-        agg = tree_mean([self.store.get(r) for r in rd["refs"]])
+        with obs.span("dagafl.eq6", client=client):
+            agg = tree_mean([self.store.get(r) for r in rd["refs"]])
         model, _ = self.backend.train_local(
             agg, self.client_data[client]["train"], seed=rd["seed"],
             epochs=self.cfg.local_epochs)
@@ -462,6 +472,11 @@ class DagAflCoordinator:
         underneath), then training/validation/signatures run as single
         vmapped programs and every result publishes at its own simulated
         completion time."""
+        self._flushes += 1
+        with obs.span("dagafl.flush", window=self._flushes, rounds=len(batch)):
+            self._dispatch_window(batch)
+
+    def _dispatch_window(self, batch) -> None:
         cfgc = self.cfg
         rounds = [self._front_half(client, t_start)
                   for client, t_start in batch]
@@ -494,11 +509,12 @@ class DagAflCoordinator:
         # stacked tip models spread over the mesh (BOTH axes of a 2-D one)
         # and one psum-einsum yields every client's Eq. 6 aggregate (see
         # core/aggregate.py)
-        stacked_tips = tree_stack([self.store.get(r) for r in uniq])
-        agg_stacked = stacked_weighted(stacked_tips, weights,
-                                       mesh=self.cohort.mesh,
-                                       axis_name=self.cohort.clients_axis,
-                                       data_axis=self.cohort.data_axis)
+        with obs.span("dagafl.eq6", window=self._flushes):
+            stacked_tips = tree_stack([self.store.get(r) for r in uniq])
+            agg_stacked = stacked_weighted(
+                stacked_tips, weights, mesh=self.cohort.mesh,
+                axis_name=self.cohort.clients_axis,
+                data_axis=self.cohort.data_axis)
 
         # batched local training + validation + signature extraction
         val_sets = [self.client_data[rd["client"]]["val"] for rd in rounds]
@@ -509,7 +525,8 @@ class DagAflCoordinator:
                                                        new_stacked)
         val_accs = self.cohort.evaluate_cohort_stacked(new_stacked, val_sets)
         sigs = self.cohort.signature_cohort_stacked(new_stacked, train_sets)
-        new_models = tree_unstack(new_stacked)
+        with obs.span("dagafl.unstack", window=self._flushes):
+            new_models = tree_unstack(new_stacked)
         self._cohorts_dispatched += 1
 
         # publish each round at ITS OWN simulated completion time

@@ -39,6 +39,8 @@ from dataclasses import dataclass, field
 from typing import (Callable, Dict, Iterable, Iterator, List, Optional,
                     Sequence, Tuple)
 
+from repro import obs
+
 try:  # py3.8+: typing.Protocol
     from typing import Protocol, runtime_checkable
 except ImportError:  # pragma: no cover
@@ -236,10 +238,12 @@ class DAGLedger:
 
     def add_transaction(self, metadata: TxMetadata, parents: Sequence[str],
                         timestamp: float, model_ref: str = "") -> Transaction:
-        for p in parents:
-            if not self._parent_known(p):
-                raise KeyError(f"unknown parent {p}")
-        return self._make_tx(metadata, tuple(parents), timestamp, model_ref)
+        with obs.span("dagafl.ledger_append"):
+            for p in parents:
+                if not self._parent_known(p):
+                    raise KeyError(f"unknown parent {p}")
+            return self._make_tx(metadata, tuple(parents), timestamp,
+                                 model_ref)
 
     def _parent_known(self, tx_id: str) -> bool:
         return tx_id in self.nodes
@@ -557,7 +561,8 @@ class BoundedDAGLedger(DAGLedger):
         (the coordinator's simulated-clock cadence hook)."""
         if self._appends_since_ckpt < min_appends:
             return None
-        return self.checkpoint(now)
+        with obs.span("dagafl.ledger_checkpoint"):
+            return self.checkpoint(now)
 
     def checkpoint(self, now: float = 0.0) -> Optional[CheckpointRecord]:
         """Fold the currently confirmed region into a checkpoint record and

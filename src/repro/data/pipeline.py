@@ -22,6 +22,7 @@ from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.data.synthetic import make_lm_dataset
 
 
@@ -239,12 +240,13 @@ class WindowAssembler:
         """The prefetched window when it matches this request, else inline
         assembly (identical output either way)."""
         pending, self._pending = self._pending, None
-        if pending is not None:
-            key, fut = pending
-            if key == self._key(datasets, seeds, epochs, cohort_target):
-                return fut.result()
-            fut.result()             # stale prefetch: settle, then discard
-        return self.assemble(datasets, seeds, epochs, cohort_target)
+        with obs.span("dagafl.assembler_wait"):
+            if pending is not None:
+                key, fut = pending
+                if key == self._key(datasets, seeds, epochs, cohort_target):
+                    return fut.result()
+                fut.result()         # stale prefetch: settle, then discard
+            return self.assemble(datasets, seeds, epochs, cohort_target)
 
     def _drain_pending(self) -> None:
         pending, self._pending = self._pending, None

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.configs.cnn import CNNConfig
 from repro.data.synthetic import Dataset
@@ -94,23 +95,26 @@ class CNNBackend:
 
     def train_local(self, params, ds: Dataset, seed: int = 0,
                     epochs: Optional[int] = None):
-        rng = np.random.default_rng(seed)
-        opt_state = self.init_opt(params)
-        loss = jnp.zeros(())
-        for _ in range(epochs or self.local_epochs):
-            xb, yb = self._batches(ds, rng)
-            params, opt_state, loss = self._train_epoch(params, opt_state,
-                                                        xb, yb)
-        return params, float(loss)
+        with obs.span("dagafl.train", rounds=1):
+            rng = np.random.default_rng(seed)
+            opt_state = self.init_opt(params)
+            loss = jnp.zeros(())
+            for _ in range(epochs or self.local_epochs):
+                xb, yb = self._batches(ds, rng)
+                params, opt_state, loss = self._train_epoch(params, opt_state,
+                                                            xb, yb)
+            return params, float(obs.fetch(loss))
 
     def evaluate(self, params, ds: Dataset, limit: int = 512) -> float:
-        n = min(len(ds), limit)
-        return float(self._eval(params, jnp.asarray(ds.x[:n]),
-                                jnp.asarray(ds.y[:n])))
+        with obs.span("dagafl.eval", rounds=1):
+            n = min(len(ds), limit)
+            return float(obs.fetch(self._eval(params, jnp.asarray(ds.x[:n]),
+                                              jnp.asarray(ds.y[:n]))))
 
     def signature(self, params, ds: Dataset, limit: int = 128) -> np.ndarray:
-        n = min(len(ds), limit)
-        return np.asarray(self._signature(params, jnp.asarray(ds.x[:n])))
+        with obs.span("dagafl.signature", rounds=1):
+            n = min(len(ds), limit)
+            return obs.fetch(self._signature(params, jnp.asarray(ds.x[:n])))
 
 
 class LMBackend:
@@ -175,20 +179,23 @@ class LMBackend:
 
     def train_local(self, params, stream: np.ndarray, seed: int = 0,
                     epochs: Optional[int] = None):
-        rng = np.random.default_rng(seed)
-        toks = self._sample(stream, rng, epochs or self.local_steps)
-        opt_state = self.opt.init(params)
-        params, _, loss = self._train_steps(params, opt_state, toks)
-        return params, float(loss)
+        with obs.span("dagafl.train", rounds=1):
+            rng = np.random.default_rng(seed)
+            toks = self._sample(stream, rng, epochs or self.local_steps)
+            opt_state = self.opt.init(params)
+            params, _, loss = self._train_steps(params, opt_state, toks)
+            return params, float(obs.fetch(loss))
 
     def evaluate(self, params, stream: np.ndarray, seed: int = 1) -> float:
-        rng = np.random.default_rng(seed)
-        toks = self._sample(stream, rng, 1)[0]
-        acc, _ = self._eval(params, toks)
-        return float(acc)
+        with obs.span("dagafl.eval", rounds=1):
+            rng = np.random.default_rng(seed)
+            toks = self._sample(stream, rng, 1)[0]
+            acc, _ = self._eval(params, toks)
+            return float(obs.fetch(acc))
 
     def signature(self, params, stream: np.ndarray, seed: int = 2) -> np.ndarray:
-        rng = np.random.default_rng(seed)
-        toks = self._sample(stream, rng, 1)[0]
-        _, sig = self._eval(params, toks)
-        return np.asarray(sig)
+        with obs.span("dagafl.signature", rounds=1):
+            rng = np.random.default_rng(seed)
+            toks = self._sample(stream, rng, 1)[0]
+            _, sig = self._eval(params, toks)
+            return obs.fetch(sig)

@@ -89,6 +89,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.aggregate import (next_pow2, pad_leading, round_up_multiple,
                                   tree_stack, tree_unstack)
 from repro.fl.backend import CNNBackend, LMBackend
@@ -1084,6 +1085,11 @@ class CohortBackend:
         ``losses[k]`` matches the sequential path's per-backend contract
         (see ``CohortPrograms.summarize_losses``).
         """
+        with obs.span("dagafl.train", rounds=len(datasets)):
+            return self._train_window(stacked_params, datasets, seeds,
+                                      epochs)
+
+    def _train_window(self, stacked_params, datasets, seeds, epochs):
         epochs = epochs or self.programs.default_epochs
         k = len(datasets)
         target = self._cohort_target(k)
@@ -1116,7 +1122,7 @@ class CohortBackend:
         else:
             new_params, losses = self._train_jit(stacked_params, win.xb,
                                                  win.yb, win.mask)
-        losses = np.asarray(losses)
+        losses = obs.fetch(losses)
         final = self.programs.summarize_losses(losses, win.steps, epochs)
         if k < losses.shape[0]:
             new_params = jax.tree_util.tree_map(lambda l: l[:k], new_params)
@@ -1131,12 +1137,12 @@ class CohortBackend:
     def evaluate_cohort_stacked(self, stacked_params, datasets,
                                 limit: int = 512) -> List[float]:
         """K models, each on its own (ragged) shard."""
-        x, y, mask = self._eval_arrays(datasets, limit)
-        k = x.shape[0]
-        stacked_params, x, y, mask, k = self._pad_cohort(
-            stacked_params, x, y, mask)
-        accs = self._eval_jit(stacked_params, x, y, mask)
-        return [float(a) for a in np.asarray(accs)[:k]]
+        with obs.span("dagafl.eval", rounds=len(datasets)):
+            x, y, mask = self._eval_arrays(datasets, limit)
+            stacked_params, x, y, mask, k = self._pad_cohort(
+                stacked_params, x, y, mask)
+            accs = self._eval_jit(stacked_params, x, y, mask)
+            return [float(a) for a in obs.fetch(accs)[:k]]
 
     def evaluate_cohort(self, params_list, datasets,
                         limit: int = 512) -> List[float]:
@@ -1153,7 +1159,7 @@ class CohortBackend:
             x, y, mask = pad_leading(x, t), pad_leading(y, t), \
                 pad_leading(mask, t)
         accs = self._eval_shared_jit(params, x, y, mask)
-        return [float(a) for a in np.asarray(accs)[:k]]
+        return [float(a) for a in obs.fetch(accs)[:k]]
 
     def evaluate_many(self, params_list, ds, limit: int = 512) -> List[float]:
         """M candidate models on one validation shard (tip selection).
@@ -1164,32 +1170,34 @@ class CohortBackend:
         m = len(params_list)
         if m == 0:
             return []
-        if m <= self.programs.eval_many_min_batch:
-            # tiny sweeps: the backend's own jitted program wins — no
-            # stacking, no pow2 model-axis padding, and it shares the
-            # sequential jit cache (threshold is suite-specific)
-            return [self.programs.evaluate_one(p, ds, limit)
-                    for p in params_list]
-        m_pad = next_pow2(m)
-        if self._n_shards > 1:
-            m_pad = round_up_multiple(m_pad, self._n_shards)
-        padded = list(params_list) + [params_list[-1]] * (m_pad - m)
-        # sample axis padded to the shared eval target: compilations stay
-        # bounded at log2(M) programs even with ragged validation shards
-        x, y, mask = self._eval_arrays([ds], limit)
-        accs = self._eval_many_jit(tree_stack(padded), x[0], y[0], mask[0])
-        return [float(a) for a in np.asarray(accs)[:m]]
+        with obs.span("dagafl.eval_many", candidates=m):
+            if m <= self.programs.eval_many_min_batch:
+                # tiny sweeps: the backend's own jitted program wins — no
+                # stacking, no pow2 model-axis padding, and it shares the
+                # sequential jit cache (threshold is suite-specific)
+                return [self.programs.evaluate_one(p, ds, limit)
+                        for p in params_list]
+            m_pad = next_pow2(m)
+            if self._n_shards > 1:
+                m_pad = round_up_multiple(m_pad, self._n_shards)
+            padded = list(params_list) + [params_list[-1]] * (m_pad - m)
+            # sample axis padded to the shared eval target: compilations stay
+            # bounded at log2(M) programs even with ragged validation shards
+            x, y, mask = self._eval_arrays([ds], limit)
+            accs = self._eval_many_jit(tree_stack(padded), x[0], y[0], mask[0])
+            return [float(a) for a in obs.fetch(accs)[:m]]
 
     def signature_cohort_stacked(self, stacked_params, datasets,
                                  limit: int = 128) -> np.ndarray:
         """(K, dims) Eq. 3 signatures, one masked batched dispatch."""
-        x, _, mask = self._eval_arrays(datasets, limit, kind="sig")
-        # pass mask in the label slot: _pad_cohort pads a (K, N) array there,
-        # not a second full copy of the (K, N, ...) sample batch
-        stacked_params, x, _, mask, k = self._pad_cohort(
-            stacked_params, x, mask, mask)
-        sigs = self._sig_jit(stacked_params, x, mask)
-        return np.asarray(sigs)[:k]
+        with obs.span("dagafl.signature", rounds=len(datasets)):
+            x, _, mask = self._eval_arrays(datasets, limit, kind="sig")
+            # pass mask in the label slot: _pad_cohort pads a (K, N) array
+            # there, not a second full copy of the (K, N, ...) sample batch
+            stacked_params, x, _, mask, k = self._pad_cohort(
+                stacked_params, x, mask, mask)
+            sigs = self._sig_jit(stacked_params, x, mask)
+            return obs.fetch(sigs)[:k]
 
     def signature_cohort(self, params_list, datasets,
                          limit: int = 128) -> np.ndarray:

@@ -33,7 +33,6 @@ event schedule — same seed, same config, same lag histogram, every run.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -349,7 +348,6 @@ class QueryStream:
         self.seq_lags: List[int] = []
         self.time_lags: List[float] = []
         self.version_hist: Dict[int, int] = {}
-        self.wall_s = 0.0
 
     def start(self) -> None:
         self.loop.schedule_stream(
@@ -368,11 +366,7 @@ class QueryStream:
         self.time_lags.append(self.loop.now - rep.published_at)
         self.version_hist[rep.version] = \
             self.version_hist.get(rep.version, 0) + 1
-        # wall-clock spent INSIDE the driver only — reported as throughput,
-        # never gated, and never fed back into simulated event times
-        t0 = time.time()      # repro-lint: disable=DET003
         self.driver.serve(rep)
-        self.wall_s += time.time() - t0   # repro-lint: disable=DET003
         self.queries += 1
 
     def report(self) -> Dict:
@@ -389,8 +383,5 @@ class QueryStream:
             "max_time_lag": max(self.time_lags) if self.time_lags else 0.0,
             "mean_time_lag": (float(np.mean(self.time_lags))
                               if self.time_lags else 0.0),
-            # wall-clock throughput: reported for eyeballing, NEVER gated
-            "query_wall_s": self.wall_s,
-            "queries_per_s": self.queries / self.wall_s if self.wall_s else 0.0,
             **self.driver.report(),
         }

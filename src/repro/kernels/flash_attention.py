@@ -129,5 +129,6 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = -1,
             pltpu.VMEM((bq,), jnp.float32),      # l
         ],
         interpret=interpret,
+        name="dagafl_flash_attention",
     )(q, k, v)
     return out[:, :, :S] if pad_q else out

@@ -151,6 +151,7 @@ def mlstm_chunkwise_bshd(q, k, v, i_gate, f_gate, *, chunk: int = 128,
             pltpu.VMEM((1,), jnp.float32),
         ],
         interpret=interpret,
+        name="dagafl_mlstm",
     )(qt, kt, vt, it, ft)
     h = h.transpose(0, 2, 1, 3)
     if pad:

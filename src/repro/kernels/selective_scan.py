@@ -90,5 +90,6 @@ def selective_scan_bsd(x, dt, A, Bc, Cc, h0, *, chunk: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((d_in, N), jnp.float32)],
         interpret=interpret,
+        name="dagafl_selective_scan",
     )(x, dt, A, Bc, Cc, h0)
     return (y[:, :S] if pad else y), h_last

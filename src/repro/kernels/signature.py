@@ -85,6 +85,7 @@ def signature_ntd(x, *, tau: float = 0.05, block_t: int = 256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="dagafl_signature",
     )(x)
     return out[:, 0]
 

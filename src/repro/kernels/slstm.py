@@ -122,5 +122,6 @@ def slstm_scan_bsd(gates_x, R, c0, n0, h0, m0, *, chunk: int = 256,
         scratch_shapes=[pltpu.VMEM((d, d4), jnp.float32),
                         pltpu.VMEM((4, d), jnp.float32)],
         interpret=interpret,
+        name="dagafl_slstm",
     )(gates_x, R, c0, n0, h0, m0)
     return (hs[:, :S] if pad else hs), (cf, nf, hf, mf)

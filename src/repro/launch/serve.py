@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs import ARCH_IDS, get_config, reduced
 from repro.models import transformer as tfm
 from repro.models.attention import cache_seq_axis
@@ -64,23 +65,25 @@ def greedy_decode(prefill_fn, decode_fn, cfg, params, batch,
     f32 — the top-2 logit gap behind each greedy pick, prefill_s,
     decode_s}; both clock reads are synced on the device results."""
     prompt_len = batch["tokens"].shape[1]
-    t0 = time.time()
-    last_logits, caches = prefill_fn(params, batch)
-    caches = extend_caches(caches, cfg, new_tokens)
-    jax.block_until_ready(last_logits)
-    t_prefill = time.time() - t0
+    with obs.span("dagafl.serve_prefill"):
+        t0 = time.time()
+        last_logits, caches = prefill_fn(params, batch)
+        caches = extend_caches(caches, cfg, new_tokens)
+        jax.block_until_ready(last_logits)
+        t_prefill = time.time() - t0
 
     tok = jnp.argmax(last_logits, -1).astype(jnp.int32)[:, None]
     generated, step_logits = [tok], [last_logits]
-    t0 = time.time()
-    for step in range(new_tokens - 1):
-        pos = jnp.int32(prompt_len + step)
-        tok, logits, caches = decode_fn(params, tok, caches, pos)
-        tok = tok[:, None] if tok.ndim == 1 else tok
-        generated.append(tok)
-        step_logits.append(logits)
-    jax.block_until_ready(tok)
-    t_decode = time.time() - t0
+    with obs.span("dagafl.serve_decode"):
+        t0 = time.time()
+        for step in range(new_tokens - 1):
+            pos = jnp.int32(prompt_len + step)
+            tok, logits, caches = decode_fn(params, tok, caches, pos)
+            tok = tok[:, None] if tok.ndim == 1 else tok
+            generated.append(tok)
+            step_logits.append(logits)
+        jax.block_until_ready(tok)
+        t_decode = time.time() - t0
     top2 = jax.lax.top_k(jnp.stack(step_logits, axis=1), 2)[0]
     return {"tokens": jnp.concatenate(generated, axis=1),
             "margins": top2[..., 0] - top2[..., 1],
