@@ -11,6 +11,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+import weakref
 from typing import Optional
 
 import jax
@@ -47,14 +48,114 @@ def extend_caches(caches, cfg, extra: int):
     return out
 
 
+class ServingWeights:
+    """The compute-dtype copy of a served model's weights, made once per
+    parameter tree and shared by a serving pair.
+
+    Called with a tree, it returns the tree the serving programs take: the
+    leaves that the model casts to ``cfg.compute_dtype`` before use
+    (:func:`repro.models.transformer.compute_weight_mask`) already cast,
+    and every other leaf the same object.  The first call with a tree casts
+    it with one program (``serve_weights``); later calls with the same
+    tree (every leaf the identical, undeleted array) reuse that copy.  One
+    copy is held at a time, keyed on weak references, so a replaced model is
+    never kept alive; a new tree drops the old copy before casting.  Where
+    no leaf's dtype differs from the compute dtype, the tree is returned
+    as it is."""
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.compute = compute = jnp.dtype(cfg.compute_dtype)
+
+        def serve_weights(leaves):
+            return [a.astype(compute) for a in leaves]
+
+        self._cast = jax.jit(serve_weights)
+        self._source = ()     # weak references to the copied tree's leaves
+        self._copy = ()       # (positions of the cast leaves, their casts)
+
+    def _to_cast(self, leaves, params):
+        """Positions of the leaves to cast: the model casts them and their
+        dtype is not the compute dtype."""
+        mask = jax.tree_util.tree_leaves(tfm.compute_weight_mask(params,
+                                                                 self.cfg))
+        return [i for i, (a, m) in enumerate(zip(leaves, mask))
+                if m and a.dtype != self.compute]
+
+    def _holds(self, leaves) -> bool:
+        return len(leaves) == len(self._source) and all(
+            r() is a and not (isinstance(a, jax.Array) and a.is_deleted())
+            for r, a in zip(self._source, leaves))
+
+    def __call__(self, params):
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        if self._copy and self._holds(leaves):
+            obs.count("dagafl.serve_weight_reuses")
+            idx, cast = self._copy
+        else:
+            idx = self._to_cast(leaves, params)
+            if not idx:
+                return params
+            if any(isinstance(a, jax.core.Tracer) for a in leaves):
+                # inside another trace: cast inline, hold nothing
+                cast = self._cast([leaves[i] for i in idx])
+            else:
+                self._source = self._copy = ()
+                obs.count("dagafl.serve_weight_casts")
+                with obs.span("dagafl.serve_cast"):
+                    cast = self._cast([leaves[i] for i in idx])
+                self._source = tuple(weakref.ref(a) for a in leaves)
+                self._copy = (idx, cast)
+        return treedef.unflatten(_replace(leaves, idx, cast))
+
+    def shapes(self, params):
+        """The tree the serving programs take, as shapes: the leaves to
+        cast become ``ShapeDtypeStruct``s of the compute dtype (keeping
+        their sharding).  Casts nothing and holds no copy."""
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        idx = self._to_cast(leaves, params)
+        cast = [jax.ShapeDtypeStruct(
+            leaves[i].shape, self.compute,
+            sharding=getattr(leaves[i], "sharding", None)) for i in idx]
+        return treedef.unflatten(_replace(leaves, idx, cast))
+
+
+def _replace(leaves, idx, new) -> list:
+    """``leaves`` with ``new[k]`` at position ``idx[k]``."""
+    out = list(leaves)
+    for i, a in zip(idx, new):
+        out[i] = a
+    return out
+
+
+class _ServingProgram:
+    """A jitted serving program that takes the weights through the pair's
+    :class:`ServingWeights`.  ``lower`` lowers the program the replica runs,
+    on the compute-dtype shapes, and casts nothing."""
+
+    def __init__(self, jitted, weights: ServingWeights):
+        self.jitted, self.weights = jitted, weights
+
+    def __call__(self, params, *args):
+        return self.jitted(self.weights(params), *args)
+
+    def lower(self, params, *args):
+        return self.jitted.lower(self.weights.shapes(params), *args)
+
+
 def make_serving_fns(cfg, runtime: Optional[Runtime] = None):
-    """The jitted (prefill, decode) pair for one arch config.  ``runtime``
-    carries the kernel-dispatch policy (see :func:`repro.runtime.
-    serve_runtime`); the decode step has no static arguments — every input
-    (params, token, caches, pos) is traced."""
+    """The jitted (prefill, decode) pair for one arch config, sharing one
+    :class:`ServingWeights`: the weights are cast to the compute dtype
+    once per parameter tree, not in every call.  ``runtime`` carries the
+    kernel-dispatch policy (see :func:`repro.runtime.serve_runtime`); the
+    decode step has no static arguments — every input (params, token,
+    caches, pos) is traced."""
     runtime = Runtime() if runtime is None else runtime
-    prefill = jax.jit(make_serve_prefill(cfg, runtime))
-    decode = jax.jit(make_serve_decode(cfg, runtime))
+    weights = ServingWeights(cfg)
+    prefill = _ServingProgram(jax.jit(make_serve_prefill(cfg, runtime)),
+                              weights)
+    decode = _ServingProgram(jax.jit(make_serve_decode(cfg, runtime)),
+                             weights)
     return prefill, decode
 
 

@@ -251,6 +251,13 @@ def scaled_attention(q, k, v, q_pos, k_pos, *, causal: bool, window: int,
 # ---------------------------------------------------------------------------
 
 
+# The leaves the GQA, cross-attention and MLA paths cast to the compute
+# dtype; MLA's q_norm / kv_norm are read as stored.
+COMPUTE_CAST = dict.fromkeys(
+    ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "xwq", "xwk", "xwv", "xwo",
+     "wq_a", "wq_b", "wkv_a", "wkv_b"), True)
+
+
 def _project_qkv(params, x, cfg: ArchConfig, compute_dtype):
     xc = x.astype(compute_dtype)
     q = xc @ params["wq"].astype(compute_dtype)

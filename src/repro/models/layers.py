@@ -44,6 +44,21 @@ def init_norm(kind: str, d: int, dtype):
     return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
 
 
+def compute_cast_mask(tree, cast):
+    """``tree``'s structure with True at each leaf that ``cast`` names.
+
+    ``cast`` mirrors the part of ``tree`` that a module casts to the compute
+    dtype before use: True at such a leaf, a dict or list at a group.  A
+    leaf it does not name (norms, leaves read in float32) is False."""
+    if isinstance(tree, dict):
+        cast = cast if isinstance(cast, dict) else {}
+        return {k: compute_cast_mask(v, cast.get(k)) for k, v in tree.items()}
+    if isinstance(tree, list):
+        cast = cast if isinstance(cast, list) else [None] * len(tree)
+        return [compute_cast_mask(v, c) for v, c in zip(tree, cast)]
+    return cast is True
+
+
 def apply_norm(params, x, kind: str, eps: float = 1e-6):
     xf = x.astype(jnp.float32)
     if kind == "rmsnorm":
@@ -89,6 +104,10 @@ def init_mlp(key, d: int, d_ff: int, dtype):
         "wi": dense_init(k2, d, d_ff, dtype),
         "wdown": dense_init(k3, d_ff, d, dtype),
     }
+
+
+# The leaves apply_mlp casts to the compute dtype.
+MLP_COMPUTE_CAST = dict.fromkeys(("wg", "wi", "wdown"), True)
 
 
 def apply_mlp(params, x, act: str, compute_dtype, sc=None):
@@ -157,6 +176,10 @@ def init_embedding(key, vocab: int, d: int, dtype, tied: bool):
     if not tied:
         p["unembed"] = dense_init(k2, d, vocab, dtype, scale=0.02)
     return p
+
+
+# The leaves embed_tokens and unembed cast to the compute dtype.
+EMBED_COMPUTE_CAST = dict.fromkeys(("embedding", "unembed"), True)
 
 
 def embed_tokens(params, tokens, compute_dtype):

@@ -51,6 +51,12 @@ def init_mamba_state(cfg: ArchConfig, batch: int, leading: tuple = ()):
     }
 
 
+# The leaves the block casts to the compute dtype; dt_proj, dt_bias, A_log
+# and D are read in float32.
+COMPUTE_CAST = dict.fromkeys(
+    ("in_proj", "conv_w", "conv_b", "x_proj", "out_proj"), True)
+
+
 def _ssm_params(params, xb, cfg, compute):
     """xb (..., d_in) conv-activated input -> dt (softplus), B, C."""
     mc, d_in, dt_rank = _dims(cfg)

@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, MoEConfig
-from repro.models.layers import activation, dense_init, init_mlp, apply_mlp
+from repro.models.layers import (MLP_COMPUTE_CAST, activation, apply_mlp,
+                                 dense_init, init_mlp)
 
 _GROUP = 512
 
@@ -38,6 +39,13 @@ def _expert_init(key, e, din, dout, dtype):
     import math
     return (jax.random.normal(key, (e, din, dout), jnp.float32)
             / math.sqrt(din)).astype(dtype)
+
+
+# The leaves moe_forward casts to the compute dtype: router, experts and
+# the shared MLP.
+COMPUTE_CAST = {**dict.fromkeys(("router", "we_gate", "we_up", "we_down"),
+                                True),
+                "shared": MLP_COMPUTE_CAST}
 
 
 def moe_forward(params, x, *, cfg: ArchConfig, sc=None,

@@ -22,9 +22,10 @@ from repro.models import attention as attn
 from repro.models import mamba as mam
 from repro.models import moe as moe_mod
 from repro.models import xlstm as xl
-from repro.models.layers import (apply_mlp, apply_norm, cross_entropy,
-                                 embed_tokens, init_embedding, init_mlp,
-                                 init_norm, unembed)
+from repro.models.layers import (EMBED_COMPUTE_CAST, MLP_COMPUTE_CAST,
+                                 apply_mlp, apply_norm, compute_cast_mask,
+                                 cross_entropy, embed_tokens, init_embedding,
+                                 init_mlp, init_norm, unembed)
 from repro.runtime import DEFAULT, Runtime
 
 
@@ -130,6 +131,30 @@ def _init_encoder(key, cfg: ArchConfig, dtype):
 
     return {"layers": jax.vmap(one)(keys[:e.n_layers]),
             "final_norm": init_norm(cfg.norm, cfg.d_model, dtype)}
+
+
+_CORE_COMPUTE_CAST = {"attn": attn.COMPUTE_CAST, "mamba": mam.COMPUTE_CAST,
+                      "mlstm": xl.MLSTM_COMPUTE_CAST,
+                      "slstm": xl.SLSTM_COMPUTE_CAST}
+
+
+def _layer_compute_cast(spec: LayerSpec):
+    return {"core": _CORE_COMPUTE_CAST[spec.kind],
+            "ffn": moe_mod.COMPUTE_CAST if spec.ffn == "moe"
+            else MLP_COMPUTE_CAST}
+
+
+def compute_weight_mask(params, cfg: ArchConfig):
+    """``params``' structure with True at each leaf that the forward,
+    prefill and decode paths cast to ``cfg.compute_dtype`` before use, as
+    each block module declares it; False at the leaves read as stored."""
+    cast = {"embed": EMBED_COMPUTE_CAST,
+            "stages": [{f"l{j}": _layer_compute_cast(spec)
+                        for j, spec in enumerate(stage.pattern)}
+                       for stage in cfg.stages],
+            "encoder": {"layers": _layer_compute_cast(
+                LayerSpec(kind="attn", ffn="dense"))}}
+    return compute_cast_mask(params, cast)
 
 
 # ---------------------------------------------------------------------------

@@ -60,6 +60,13 @@ def init_mlstm_state(cfg: ArchConfig, batch: int, leading: tuple = ()):
     }
 
 
+# The leaves the mLSTM block casts to the compute dtype; b_if and head_norm
+# are read as stored.
+MLSTM_COMPUTE_CAST = dict.fromkeys(
+    ("up_proj", "conv_w", "conv_b", "wq", "wk", "wv", "w_if", "down_proj"),
+    True)
+
+
 def _mlstm_qkvif(params, x, cfg, compute):
     """x (B,S,d) -> q,k (B,S,H,dqk/H), v (B,S,H,dv/H), i,f (B,S,H), z (B,S,d_in)."""
     xc, d_in, d_qk, H = _mdims(cfg)
@@ -252,6 +259,12 @@ def init_slstm_state(cfg: ArchConfig, batch: int, leading: tuple = ()):
     return {"c": z(), "n": z(), "h": z(),
             "m": jnp.full(leading + (batch, d), -1e30, jnp.float32),
             "conv": jnp.zeros(leading + (batch, xc.s_conv - 1, d), jnp.float32)}
+
+
+# The leaves the sLSTM block casts to the compute dtype; the gate weights
+# (w_gates, r_gates, b_gates) and out_norm are read in float32.
+SLSTM_COMPUTE_CAST = dict.fromkeys(
+    ("conv_w", "conv_b", "up_proj", "down_proj"), True)
 
 
 def _slstm_scan_maybe_sharded(params, xconv, x_raw, state, compute, runtime):
