@@ -244,7 +244,7 @@ def phase_b(cfg, *, policy: str, expect_custom_call: bool, batch: int = 4,
         greedy_decode(prefill, decode, cfg, params, prompts, new_tokens)
         first_s = time.perf_counter() - t0          # includes compiles
         r = greedy_decode(prefill, decode, cfg, params, prompts, new_tokens)
-        logits, _ = prefill(params, prompts)
+        logits = prefill(params, prompts)[0]
         out[name] = {
             "first_call_s": first_s, "prefill_s": r["prefill_s"],
             "decode_s": r["decode_s"],

@@ -6,7 +6,7 @@ import importlib
 from repro.configs.base import (ArchConfig, EncoderConfig, InputShape,
                                 INPUT_SHAPES, LayerSpec, MLAConfig,
                                 MambaConfig, MoEConfig, Stage, XLSTMConfig,
-                                reduced)
+                                YarnConfig, reduced)
 from repro.configs.cnn import CNNConfig, VGG16, VGG_TINY, vgg_for
 
 _ARCH_MODULES = {
@@ -39,6 +39,6 @@ def all_configs():
 __all__ = [
     "ArchConfig", "CNNConfig", "EncoderConfig", "InputShape", "INPUT_SHAPES",
     "LayerSpec", "MLAConfig", "MambaConfig", "MoEConfig", "Stage",
-    "XLSTMConfig", "ARCH_IDS", "get_config", "all_configs", "reduced",
+    "XLSTMConfig", "YarnConfig", "ARCH_IDS", "get_config", "all_configs", "reduced",
     "VGG16", "VGG_TINY", "vgg_for",
 ]

@@ -10,6 +10,7 @@ global, jamba mamba:attn, llama4 dense:MoE, deepseek first-dense-layer).
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -20,15 +21,34 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts FFN settings (GSPMD-style capacity dispatch)."""
+    """Mixture-of-experts FFN settings.
 
-    n_experts: int
+    Training dispatches with a capacity (GSPMD style); serving computes
+    every routed row (``models/moe.moe_serve``).  A layer may hold only a
+    share of the experts its router scores, as one chip of an
+    expert-parallel deployment does: the experts ``expert_offset ..
+    expert_offset + n_experts - 1`` of the router's ``n_routed``."""
+
+    n_experts: int                # experts held (parameters) here
     top_k: int
     d_expert: int                 # per-expert hidden width
     n_shared: int = 0             # always-on shared experts (DeepSeek-V2)
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01   # load-balance aux loss weight
     router_z_weight: float = 1e-3     # router z-loss weight
+    n_routed: int = 0             # router outputs; 0 => n_experts
+    expert_offset: int = 0        # router index of the first held expert
+    # group-limited greedy routing (DeepSeek-V2 device-limited routing):
+    # the best ``topk_group`` of ``n_group`` expert groups by their top
+    # score, then the top_k experts within them; 1/1 is plain top-k
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk: bool = True        # gates renormalised to sum to one
+    routed_scale: float = 1.0     # gates times this when not renormalised
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -40,6 +60,19 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 applies it to
+    the rotary part of its MLA heads."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -74,6 +107,7 @@ class LayerSpec:
     window: int = -1              # -1 => full causal attention; >0 sliding
     ffn: str = "dense"            # dense | moe | none
     cross_attn: bool = False      # decoder cross-attention (whisper)
+    d_ff: int = 0                 # dense FFN width; 0 => ArchConfig.d_ff
 
 
 @dataclass(frozen=True)
@@ -120,6 +154,7 @@ class ArchConfig:
     attn_softcap: float = 0.0     # gemma2 attention logit soft-cap
     final_softcap: float = 0.0    # gemma2 final logit soft-cap
     mla: Optional[MLAConfig] = None
+    yarn: Optional[YarnConfig] = None     # MLA rotary scaling
 
     moe: Optional[MoEConfig] = None
     mamba: Optional[MambaConfig] = None
@@ -157,6 +192,10 @@ class ArchConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    def ffn_width(self, spec: LayerSpec) -> int:
+        """Width of ``spec``'s dense FFN."""
+        return spec.d_ff or self.d_ff
 
     def layer_specs(self):
         """Flat list of LayerSpec in execution order."""
@@ -201,11 +240,11 @@ class ArchConfig:
             elif spec.kind == "slstm":
                 xc = self.xlstm or XLSTMConfig()
                 total += 4 * d * d + 4 * d * d // 4 + int(4.0 / 3 * d * d) * 2
-            if spec.ffn == "dense" and self.d_ff > 0:
-                total += 3 * d * self.d_ff
+            if spec.ffn == "dense" and self.ffn_width(spec) > 0:
+                total += 3 * d * self.ffn_width(spec)
             elif spec.ffn == "moe" and self.moe is not None:
                 mo = self.moe
-                total += d * mo.n_experts
+                total += d * mo.router_width
                 total += 3 * d * mo.d_expert * (mo.n_experts + mo.n_shared)
         if self.encoder is not None:
             e = self.encoder
@@ -249,7 +288,8 @@ INPUT_SHAPES = {
 def reduced(cfg: ArchConfig, d_model: int = 256, max_experts: int = 4) -> ArchConfig:
     """Reduced smoke-test variant of the same family: 2 layers, small dims."""
     pattern = cfg.stages[-1].pattern if cfg.stages else (LayerSpec(),)
-    pattern = pattern[: min(len(pattern), 2)]
+    pattern = tuple(dataclasses.replace(s, d_ff=0)
+                    for s in pattern[: min(len(pattern), 2)])
     repeats = -(-2 // len(pattern))  # >= 2 layers total
     n_layers = len(pattern) * repeats
     n_heads = min(cfg.n_heads, 4)
@@ -261,10 +301,13 @@ def reduced(cfg: ArchConfig, d_model: int = 256, max_experts: int = 4) -> ArchCo
         n_kv = max(n_heads // 2, 1)   # preserve GQA in the reduced family
     moe = None
     if cfg.moe is not None:
+        n_exp = min(cfg.moe.n_experts, max_experts)
+        n_group = math.gcd(cfg.moe.n_group, n_exp)
         moe = dataclasses.replace(
-            cfg.moe, n_experts=min(cfg.moe.n_experts, max_experts),
+            cfg.moe, n_experts=n_exp, n_routed=0, expert_offset=0,
             top_k=min(cfg.moe.top_k, 2), d_expert=d_model,
-            n_shared=min(cfg.moe.n_shared, 1))
+            n_shared=min(cfg.moe.n_shared, 1), n_group=n_group,
+            topk_group=min(cfg.moe.topk_group, n_group))
     mla = None
     if cfg.mla is not None:
         mla = MLAConfig(kv_lora_rank=64, qk_nope_dim=head_dim,

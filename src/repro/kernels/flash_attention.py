@@ -85,8 +85,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = -1,
                          softcap: float = 0.0, block_q: int = 128,
-                         block_k: int = 128, interpret=None):
+                         block_k: int = 128, interpret=None, scale=None):
     """q (B,H,Sq,hd); k,v (B,K,Sk,hd) with H % K == 0 (GQA).
+
+    ``scale`` multiplies the scores (None: 1/sqrt(hd)).
 
     Returns (B,H,Sq,hd) in q.dtype.  Sq must equal Sk (self-attention over
     the same positions); callers pad to block multiples.
@@ -110,7 +112,8 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = -1,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
 
     kernel = functools.partial(
-        _kernel, scale=1.0 / math.sqrt(hd), causal=causal, window=window,
+        _kernel, scale=1.0 / math.sqrt(hd) if scale is None else scale,
+        causal=causal, window=window,
         softcap=softcap, bq=bq, bk=bk, nk=nk, seq_len=S)
 
     out = pl.pallas_call(

@@ -37,14 +37,17 @@ from repro.kernels.dispatch import (KERNEL_POLICIES, POLICY_ENV,  # noqa: F401
                                     resolve_policy)
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.mlstm import mlstm_chunkwise_bshd
+from repro.kernels.moe_gmm import moe_gmm_rows
 from repro.kernels.selective_scan import selective_scan_bsd
 from repro.kernels.signature import signature_ntd
 from repro.kernels.slstm import slstm_scan_bsd
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = -1,
-                    softcap: float = 0.0, policy=None, interpret=None):
-    """(B,S,H,hd) layout wrapper used by repro.models.attention."""
+                    softcap: float = 0.0, scale=None, policy=None,
+                    interpret=None):
+    """(B,S,H,hd) layout wrapper used by repro.models.attention; ``scale``
+    multiplies the scores (None: 1/sqrt(hd))."""
     p = resolve_policy(policy)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -52,12 +55,28 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = -1,
     if p == "reference" and interpret is None:
         from repro.kernels.ref import flash_attention_ref
         out = flash_attention_ref(qt, kt, vt, causal=causal, window=window,
-                                  softcap=softcap)
+                                  softcap=softcap, scale=scale)
     else:
         out = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                                   softcap=softcap,
+                                   softcap=softcap, scale=scale,
                                    interpret=resolve_interpret(interpret, p))
     return out.transpose(0, 2, 1, 3)
+
+
+def moe_gmm(lhs, rhs, group_sizes, *, tm: int, layer=None, policy=None,
+            interpret=None):
+    """Grouped matmul of held experts (``kernels/moe_gmm.py``): lhs (M, K)
+    rows sorted by expert, each expert's rows starting on a ``tm`` tile;
+    rhs (E, K, N), or (L, E, K, N) and the ``layer`` to use; group_sizes
+    (E,) padded to multiples of ``tm``.  Only the rows inside the groups
+    are defined."""
+    p = resolve_policy(policy)
+    if p == "reference" and interpret is None:
+        from repro.kernels.ref import moe_gmm_ref
+        return moe_gmm_ref(lhs, rhs if layer is None else rhs[layer],
+                           group_sizes)
+    return moe_gmm_rows(lhs, rhs, group_sizes, tm=tm, layer=layer,
+                        interpret=resolve_interpret(interpret, p))
 
 
 def selective_scan(x, dt, A, Bc, Cc, h0, *, chunk: int = 256,

@@ -10,15 +10,17 @@ _NEG = -2.0e9
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = -1,
-                        softcap: float = 0.0):
-    """q (B,H,S,hd); k,v (B,K,S,hd); GQA by head repetition. f32 math."""
+                        softcap: float = 0.0, scale=None):
+    """q (B,H,S,hd); k,v (B,K,S,hd); GQA by head repetition. f32 math.
+    ``scale`` multiplies the scores (None: divide by sqrt(hd))."""
     B, H, S, hd = q.shape
     K = k.shape[1]
     G = H // K
     k = jnp.repeat(k, G, axis=1)
     v = jnp.repeat(v, G, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) / math.sqrt(hd)
+                   k.astype(jnp.float32))
+    s = s / math.sqrt(hd) if scale is None else s * scale
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
     rows = jnp.arange(S)[:, None]
@@ -32,6 +34,15 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = -1,
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", w, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def moe_gmm_ref(lhs, rhs, group_sizes):
+    """Oracle of ``moe_gmm``: rows in consecutive groups of
+    ``group_sizes``, each times its expert's matrix, accumulated in f32;
+    rows past the groups are zero."""
+    out = jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                             preferred_element_type=jnp.float32)
+    return out.astype(lhs.dtype)
 
 
 def selective_scan_seq_ref(x, dt, A, Bc, Cc, h0):

@@ -135,7 +135,7 @@ def build_step(cfg: ArchConfig, shape: InputShape, mesh, plan: MeshPlan):
     token_sh = batch_shardings({"tokens": spec["token"]}, mesh, plan)["tokens"]
     jitted = jax.jit(fn,
                      in_shardings=(params_sh, token_sh, cache_sh, repl),
-                     out_shardings=(None, None, cache_sh))
+                     out_shardings=(None, None, cache_sh, None))
     return jitted, (params_shape, spec["token"], caches_shape, spec["pos"])
 
 

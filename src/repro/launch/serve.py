@@ -16,6 +16,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import obs
 from repro.configs import ARCH_IDS, get_config, reduced
@@ -159,36 +160,70 @@ def make_serving_fns(cfg, runtime: Optional[Runtime] = None):
     return prefill, decode
 
 
+_MOE_COUNTS = ("moe_routed_rows", "moe_experts_hit")
+
+
+def _moe_stats(prefill_stats, step_stats) -> dict:
+    """The query's expert-layer stats: per-layer counts on the host, the
+    prefill's and the decode steps' summed (their totals are added to the
+    counters ``dagafl.moe_routed_rows`` and ``dagafl.moe_experts_hit``),
+    and ``held``: each position's held experts per expert layer,
+    (L, B, prompt + new_tokens - 1, E) bool, left on the device."""
+    decode = {k: (jnp.sum(jnp.stack([s[k] for s in step_stats]), axis=0)
+                  if step_stats else jnp.zeros_like(prefill_stats[k]))
+              for k in _MOE_COUNTS}
+    host = jax.device_get({"prefill": {k: prefill_stats[k]
+                                       for k in _MOE_COUNTS},
+                           "decode": decode})
+    host = {ph: {k: np.asarray(v) for k, v in d.items()}
+            for ph, d in host.items()}
+    for k in _MOE_COUNTS:
+        obs.count(f"dagafl.{k}",
+                  int(host["prefill"][k].sum() + host["decode"][k].sum()))
+    host["held"] = jnp.concatenate(
+        [prefill_stats["moe_held"]] + [s["moe_held"] for s in step_stats],
+        axis=-2)
+    return host
+
+
 def greedy_decode(prefill_fn, decode_fn, cfg, params, batch,
                   new_tokens: int):
     """Prefill ``batch`` then greedy-decode ``new_tokens`` against the KV
     cache.  Returns {tokens (B, new_tokens) int32, margins (B, new_tokens)
     f32 — the top-2 logit gap behind each greedy pick, prefill_s,
-    decode_s}; both clock reads are synced on the device results."""
+    decode_s}; both clock reads are synced on the device results.  A model
+    with expert layers adds ``moe``: {"prefill"/"decode": per-layer
+    ``moe_routed_rows`` and ``moe_experts_hit``, read after the decode
+    clock stops; "held": the routing, see :func:`_moe_stats`}."""
     prompt_len = batch["tokens"].shape[1]
     with obs.span("dagafl.serve_prefill"):
         t0 = time.time()
-        last_logits, caches = prefill_fn(params, batch)
+        last_logits, caches, prefill_stats = prefill_fn(params, batch)
         caches = extend_caches(caches, cfg, new_tokens)
         jax.block_until_ready(last_logits)
         t_prefill = time.time() - t0
 
     tok = jnp.argmax(last_logits, -1).astype(jnp.int32)[:, None]
-    generated, step_logits = [tok], [last_logits]
+    generated, step_logits, step_stats = [tok], [last_logits], []
     with obs.span("dagafl.serve_decode"):
         t0 = time.time()
         for step in range(new_tokens - 1):
             pos = jnp.int32(prompt_len + step)
-            tok, logits, caches = decode_fn(params, tok, caches, pos)
+            tok, logits, caches, stats = decode_fn(params, tok, caches, pos)
             tok = tok[:, None] if tok.ndim == 1 else tok
             generated.append(tok)
             step_logits.append(logits)
+            if stats:
+                step_stats.append(stats)
         jax.block_until_ready(tok)
         t_decode = time.time() - t0
     top2 = jax.lax.top_k(jnp.stack(step_logits, axis=1), 2)[0]
-    return {"tokens": jnp.concatenate(generated, axis=1),
-            "margins": top2[..., 0] - top2[..., 1],
-            "prefill_s": t_prefill, "decode_s": t_decode}
+    out = {"tokens": jnp.concatenate(generated, axis=1),
+           "margins": top2[..., 0] - top2[..., 1],
+           "prefill_s": t_prefill, "decode_s": t_decode}
+    if prefill_stats:
+        out["moe"] = _moe_stats(prefill_stats, step_stats)
+    return out
 
 
 def serve(cfg, batch: int, prompt_len: int, new_tokens: int, seed: int = 0):

@@ -21,12 +21,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, LayerSpec
-from repro.models.layers import apply_norm, apply_rope, dense_init, init_norm, softcap
+from repro.models.layers import (apply_norm, apply_rope, dense_init, init_norm,
+                                 softcap, yarn_freqs, yarn_scales)
 
 _NEG = -2.0e9
 _DENSE_MAX = 2048          # above this, use chunked/banded paths
 _KV_CHUNK = 1024
 _Q_BLOCK = 512
+_MLA_TOKENS = 8192         # tokens an MLA prefill pass expands at once
 
 
 # ---------------------------------------------------------------------------
@@ -120,8 +122,9 @@ def _mask_bias(q_pos, k_pos, causal: bool, window: int):
     return jnp.where(ok, 0.0, _NEG).astype(jnp.float32)
 
 
-def _sdpa(q, k, v, bias, cap: float):
-    """q (B,Sq,H,hd) k,v (B,Sk,K,hd) bias (Sq,Sk) -> (B,Sq,H,hd).
+def _sdpa(q, k, v, bias, cap: float, scale=None):
+    """q (B,Sq,H,hd) k,v (B,Sk,K,hd) bias (Sq,Sk) -> (B,Sq,H,hd); the scores
+    are scaled by ``scale`` (None: 1/sqrt(hd)).
 
     k/v stay in their storage dtype (bf16 caches) — the MXU accumulates in
     f32 via ``preferred_element_type``, so no cache-wide f32 convert is ever
@@ -130,7 +133,8 @@ def _sdpa(q, k, v, bias, cap: float):
     B, Sq, H, hd = q.shape
     K = k.shape[2]
     G = H // K
-    qs = (q.astype(jnp.float32) * (1.0 / math.sqrt(hd))).astype(k.dtype)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
+    qs = (q.astype(jnp.float32) * scale).astype(k.dtype)
     qs = qs.reshape(B, Sq, K, G, hd)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qs, k,
                         preferred_element_type=jnp.float32)
@@ -142,12 +146,13 @@ def _sdpa(q, k, v, bias, cap: float):
     return out.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
-def _dense_attn(q, k, v, q_pos, k_pos, causal, window, cap):
+def _dense_attn(q, k, v, q_pos, k_pos, causal, window, cap, scale=None):
     bias = _mask_bias(q_pos, k_pos, causal, window)          # (B,Sq,Sk)
-    return _sdpa(q, k, v, bias, cap)
+    return _sdpa(q, k, v, bias, cap, scale)
 
 
-def _chunked_attn(q, k, v, q_pos, k_pos, causal, cap, chunk=_KV_CHUNK):
+def _chunked_attn(q, k, v, q_pos, k_pos, causal, cap, chunk=_KV_CHUNK,
+                  scale=None):
     """Online-softmax scan over kv chunks. Full causal, O(S*chunk) memory."""
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
@@ -164,7 +169,8 @@ def _chunked_attn(q, k, v, q_pos, k_pos, causal, cap, chunk=_KV_CHUNK):
     vc = v.reshape(B, n_chunks, chunk, K, hd).transpose(1, 0, 2, 3, 4)
     pc = k_pos.reshape(n_chunks, chunk)
 
-    qf = ((q.astype(jnp.float32) * (1.0 / math.sqrt(hd)))
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
+    qf = ((q.astype(jnp.float32) * scale)
           .astype(k.dtype).reshape(B, Sq, K, G, hd))
 
     def body(carry, xs):
@@ -192,7 +198,8 @@ def _chunked_attn(q, k, v, q_pos, k_pos, causal, cap, chunk=_KV_CHUNK):
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd).astype(q.dtype)
 
 
-def _banded_attn(q, k, v, q_pos, k_pos, window, cap, q_block=_Q_BLOCK):
+def _banded_attn(q, k, v, q_pos, k_pos, window, cap, q_block=_Q_BLOCK,
+                 scale=None):
     """Sliding-window causal attention: scan over q blocks, static kv band."""
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
@@ -219,7 +226,7 @@ def _banded_attn(q, k, v, q_pos, k_pos, window, cap, q_block=_Q_BLOCK):
         vb = jax.lax.dynamic_slice_in_dim(v, start, band, axis=1)
         pb = jax.lax.dynamic_slice_in_dim(k_pos, start, band, axis=0)
         bias = _mask_bias(qpos_blk, pb, True, window)        # (bq,band)
-        return _sdpa(qblk, kb, vb, bias, cap)
+        return _sdpa(qblk, kb, vb, bias, cap, scale)
 
     out = jax.lax.map(lambda xs: per_block(*xs), (idx, qb, qp))
     out = out.transpose(1, 0, 2, 3, 4).reshape(B, nq * q_block, H, hd)
@@ -227,23 +234,24 @@ def _banded_attn(q, k, v, q_pos, k_pos, window, cap, q_block=_Q_BLOCK):
 
 
 def scaled_attention(q, k, v, q_pos, k_pos, *, causal: bool, window: int,
-                     cap: float, runtime=None):
+                     cap: float, runtime=None, scale=None):
     """Dispatch over score paths (and the Pallas kernel when enabled).
 
-    q_pos (Sq,), k_pos (Sk,): 1-D global sequence positions.
+    q_pos (Sq,), k_pos (Sk,): 1-D global sequence positions; ``scale``
+    multiplies the scores (None: 1/sqrt(head_dim)).
     """
     Sq, Sk = q.shape[1], k.shape[1]
     if runtime is not None and getattr(runtime, "use_pallas", False) \
             and causal and Sq == Sk:
         from repro.kernels import ops as kops
         return kops.flash_attention(q, k, v, causal=True, window=window,
-                                    softcap=cap,
+                                    softcap=cap, scale=scale,
                                     policy=kops.policy_from_runtime(runtime))
     if window > 0 and causal and Sq == Sk and Sq > _DENSE_MAX:
-        return _banded_attn(q, k, v, q_pos, k_pos, window, cap)
+        return _banded_attn(q, k, v, q_pos, k_pos, window, cap, scale=scale)
     if max(Sq, Sk) <= _DENSE_MAX or Sq != Sk:
-        return _dense_attn(q, k, v, q_pos, k_pos, causal, window, cap)
-    return _chunked_attn(q, k, v, q_pos, k_pos, causal, cap)
+        return _dense_attn(q, k, v, q_pos, k_pos, causal, window, cap, scale)
+    return _chunked_attn(q, k, v, q_pos, k_pos, causal, cap, scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -355,6 +363,24 @@ def cross_kv(params, enc_out, *, cfg: ArchConfig):
 # ---------------------------------------------------------------------------
 
 
+def _mla_rope(x, positions, cfg: ArchConfig):
+    """Rotary embedding of MLA's decoupled rope part (B,S,H,rd), under
+    YaRN where the config has it."""
+    if cfg.yarn is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    rot, _ = yarn_scales(cfg.yarn)
+    return apply_rope(x, positions, cfg.rope_theta,
+                      inv_freq=yarn_freqs(x.shape[-1], cfg.rope_theta,
+                                          cfg.yarn), mscale=rot)
+
+
+def _mla_softmax_scale(cfg: ArchConfig) -> float:
+    """1/sqrt(nope + rope dims), times YaRN's mscale(mscale_all_dim)^2."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    return scale if cfg.yarn is None else scale * yarn_scales(cfg.yarn)[1]
+
+
 def _mla_q(params, x, cfg: ArchConfig, compute):
     m = cfg.mla
     B, S = x.shape[:2]
@@ -376,7 +402,7 @@ def _mla_latents(params, x, cfg: ArchConfig, positions, compute):
     ckv = apply_norm(params["kv_norm"], kv_a[..., :m.kv_lora_rank],
                      cfg.norm, cfg.norm_eps)
     krope = kv_a[..., m.kv_lora_rank:]                        # (B,S,rd)
-    krope = apply_rope(krope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    krope = _mla_rope(krope[:, :, None, :], positions, cfg)[:, :, 0]
     return ckv, krope
 
 
@@ -388,12 +414,44 @@ def _mla_wkvb_split(params, cfg: ArchConfig, compute):
 
 
 def _mla_forward(params, x, *, cfg: ArchConfig, positions, window, runtime=None):
+    """Full-sequence MLA with expanded keys and values.  Batches of more
+    than ``_MLA_TOKENS`` tokens run their expansion and attention a few
+    rows at a time, so the expanded q/k/v of the whole batch are never
+    held at once."""
+    compute = jnp.dtype(cfg.compute_dtype)
+    B, S = x.shape[:2]
+    ckv, krope = _mla_latents(params, x, cfg, positions, compute)
+
+    def attend(a):
+        xb, ckvb, kropeb, posb = a
+        return _mla_attend(params, xb, ckvb, kropeb, posb, cfg=cfg,
+                           window=window, runtime=runtime)
+
+    rows = max(1, min(B, _MLA_TOKENS // S))
+    while B % rows:
+        rows -= 1
+    if rows == B:
+        out = attend((x, ckv, krope, positions))
+    else:
+        def split(a):
+            return a.reshape((B // rows, rows) + a.shape[1:])
+        out = jax.lax.map(attend, tuple(split(a) for a in
+                                        (x, ckv, krope, positions)))
+        out = out.reshape((B,) + out.shape[2:])
+    cache_dt = jnp.dtype(cfg.cache_dtype)
+    return out, {"ckv": ckv.astype(cache_dt), "krope": krope.astype(cache_dt)}
+
+
+def _mla_attend(params, x, ckv, krope, positions, *, cfg: ArchConfig,
+                window, runtime):
+    """Causal MLA of rows x (b,S,d) over their latents: q, keys and values
+    expanded per head (the rope part of the keys shared), then the output
+    projection."""
     m = cfg.mla
     compute = jnp.dtype(cfg.compute_dtype)
     B, S = x.shape[:2]
     q_nope, q_rope = _mla_q(params, x, cfg, compute)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    ckv, krope = _mla_latents(params, x, cfg, positions, compute)
+    q_rope = _mla_rope(q_rope, positions, cfg)
     wk, wv = _mla_wkvb_split(params, cfg, compute)
     # expand keys/values (chunk-recomputed inside scaled_attention paths by
     # concatenating rope and nope sections into a single head_dim)
@@ -409,11 +467,10 @@ def _mla_forward(params, x, *, cfg: ArchConfig, positions, window, runtime=None)
     hd = q.shape[-1]
     v_pad = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, hd - vd))) if hd > vd else v
     out = scaled_attention(q, k, v_pad, pos1d, pos1d, causal=True,
-                           window=window, cap=0.0, runtime=runtime)
+                           window=window, cap=0.0, runtime=runtime,
+                           scale=_mla_softmax_scale(cfg))
     out = out[..., :vd].reshape(B, S, cfg.n_heads * vd)
-    out = (out.astype(compute) @ params["wo"].astype(compute)).astype(x.dtype)
-    cache_dt = jnp.dtype(cfg.cache_dtype)
-    return out, {"ckv": ckv.astype(cache_dt), "krope": krope.astype(cache_dt)}
+    return (out.astype(compute) @ params["wo"].astype(compute)).astype(x.dtype)
 
 
 def _mla_decode(params, x, cache, pos, *, cfg: ArchConfig, window: int):
@@ -424,7 +481,7 @@ def _mla_decode(params, x, cache, pos, *, cfg: ArchConfig, window: int):
     S = cache["ckv"].shape[1]
     positions = jnp.full((B, 1), pos, jnp.int32)
     q_nope, q_rope = _mla_q(params, x, cfg, compute)          # (B,1,H,*)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
     ckv_new, krope_new = _mla_latents(params, x, cfg, positions, compute)
     ckv = jax.lax.dynamic_update_slice_in_dim(
         cache["ckv"], ckv_new.astype(cache["ckv"].dtype), pos, axis=1)
@@ -434,7 +491,7 @@ def _mla_decode(params, x, cache, pos, *, cfg: ArchConfig, window: int):
     # absorb: q_eff[h,r] = sum_d q_nope[h,d] * wk[r,h,d]
     q_eff = jnp.einsum("bqhd,rhd->bqhr", q_nope, wk,
                        preferred_element_type=jnp.float32)
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _mla_softmax_scale(cfg)
     s_lat = jnp.einsum("bqhr,bsr->bhqs", q_eff.astype(ckv.dtype), ckv,
                        preferred_element_type=jnp.float32)
     s_rope = jnp.einsum("bqhd,bsd->bhqs", q_rope.astype(krope.dtype), krope,

@@ -16,6 +16,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # ---------------------------------------------------------------------------
 # init helpers
@@ -131,20 +132,63 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: ``0.1 * mscale * ln(factor) + 1``
+    (one at factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies of a rotary part of ``head_dim`` dims: the
+    base frequencies where a dim turns more than ``beta_fast`` times over
+    the original context, the frequencies divided by ``factor`` where it
+    turns fewer than ``beta_slow`` times, and a linear ramp between."""
+    def turn_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turn_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(turn_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    half = head_dim // 2
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                            / head_dim)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0.0, 1.0)
+    return (extra / yarn.factor * ramp + extra * (1.0 - ramp)).astype(
+        np.float32)
+
+
+def yarn_scales(yarn) -> tuple:
+    """(rotary scale, softmax scale factor) of YaRN: cos and sin are
+    multiplied by ``mscale(mscale) / mscale(mscale_all_dim)``, and the
+    softmax scale by ``mscale(mscale_all_dim)`` squared."""
+    all_dim = yarn_mscale(yarn.factor, yarn.mscale_all_dim) \
+        if yarn.mscale_all_dim else 1.0
+    rot = yarn_mscale(yarn.factor, yarn.mscale) / all_dim
+    return rot, all_dim * all_dim
+
+
 def _rotate_half(x):
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([-x2, x1], axis=-1)
 
 
 def apply_rope(x, positions, theta: float,
-               mrope_sections: Optional[Tuple[int, int, int]] = None):
+               mrope_sections: Optional[Tuple[int, int, int]] = None,
+               inv_freq=None, mscale: float = 1.0):
     """Rotary embedding.
 
     x: (..., S, n_heads, head_dim); positions: (B, S) int32 or (3, B, S) for
     M-RoPE (temporal/height/width ids — equal for pure-text streams).
+    ``inv_freq`` replaces the base frequencies and ``mscale`` multiplies
+    cos and sin (YaRN, :func:`yarn_freqs`).
     """
     head_dim = x.shape[-1]
-    inv = rope_freqs(head_dim, theta)                       # (half,)
+    inv = rope_freqs(head_dim, theta) if inv_freq is None \
+        else jnp.asarray(inv_freq, jnp.float32)             # (half,)
     if mrope_sections is None:
         pos = positions if positions.ndim == 2 else positions[0]
         ang = pos[..., None].astype(jnp.float32) * inv      # (B, S, half)
@@ -160,6 +204,8 @@ def apply_rope(x, positions, theta: float,
     ang = jnp.concatenate([ang, ang], axis=-1)              # (B, S, head_dim)
     cos = jnp.cos(ang)[..., None, :]                        # (B, S, 1, hd)
     sin = jnp.sin(ang)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     xf = x.astype(jnp.float32)
     out = xf * cos + _rotate_half(xf) * sin
     return out.astype(x.dtype)

@@ -90,9 +90,9 @@ def _init_layer(key, cfg: ArchConfig, spec: LayerSpec, dtype):
         raise ValueError(spec.kind)
     if spec.cross_attn:
         p["xnorm"] = init_norm(cfg.norm, cfg.d_model, dtype)
-    if spec.ffn == "dense" and cfg.d_ff > 0:
+    if spec.ffn == "dense" and cfg.ffn_width(spec) > 0:
         p["norm2"] = init_norm(cfg.norm, cfg.d_model, dtype)
-        p["ffn"] = init_mlp(k2, cfg.d_model, cfg.d_ff, dtype)
+        p["ffn"] = init_mlp(k2, cfg.d_model, cfg.ffn_width(spec), dtype)
     elif spec.ffn == "moe":
         p["norm2"] = init_norm(cfg.norm, cfg.d_model, dtype)
         p["ffn"] = moe_mod.init_moe(k3, cfg, dtype)
@@ -195,8 +195,11 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int):
 
 def _layer_forward(lp, x, *, cfg, spec, positions, window, runtime,
                    enc_out=None, causal=True, mode="train"):
-    """Full-sequence block. Returns (x, cache_out, aux_scalar)."""
+    """Full-sequence block. Returns (x, cache_out, aux_scalar, stats):
+    ``stats`` holds a serving expert layer's counts (``moe_serve``), else
+    nothing."""
     aux = jnp.zeros((), jnp.float32)
+    stats = {}
     h = apply_norm(lp["norm1"], x, cfg.norm, cfg.norm_eps)
     if spec.kind == "attn":
         if causal:
@@ -218,17 +221,20 @@ def _layer_forward(lp, x, *, cfg, spec, positions, window, runtime,
         x = x + attn.cross_attn_forward(lp["core"], h2, xk, xv, cfg=cfg)
         cache = dict(cache)
         cache["xk"], cache["xv"] = xk, xv
-    if spec.ffn == "dense" and cfg.d_ff > 0:
+    if spec.ffn == "dense" and cfg.ffn_width(spec) > 0:
         h3 = apply_norm(lp["norm2"], x, cfg.norm, cfg.norm_eps)
         y, _ = apply_mlp(lp["ffn"], h3, cfg.act, jnp.dtype(cfg.compute_dtype))
         x = x + y
-    elif spec.ffn == "moe":
+    elif spec.ffn == "moe" and mode == "train":
         h3 = apply_norm(lp["norm2"], x, cfg.norm, cfg.norm_eps)
-        y, maux = moe_mod.moe_forward(lp["ffn"], h3, cfg=cfg,
-                                      generous_capacity=(mode != "train"))
+        y, maux = moe_mod.moe_forward(lp["ffn"], h3, cfg=cfg)
         aux = aux + maux["moe_aux"]
         x = x + y
-    return x, cache, aux
+    elif spec.ffn == "moe":
+        h3 = apply_norm(lp["norm2"], x, cfg.norm, cfg.norm_eps)
+        y, stats = moe_mod.moe_serve(lp["ffn"], h3, cfg=cfg, runtime=runtime)
+        x = x + y
+    return x, cache, aux, stats
 
 
 def _encoder_attn(params, h, cfg, positions, runtime):
@@ -248,7 +254,8 @@ def _encoder_attn(params, h, cfg, positions, runtime):
 
 
 def _layer_decode(lp, x, cache, pos, *, cfg, spec, window, runtime):
-    aux = jnp.zeros((), jnp.float32)
+    """One-token block. Returns (x, new_cache, stats) as _layer_forward."""
+    stats = {}
     h = apply_norm(lp["norm1"], x, cfg.norm, cfg.norm_eps)
     if spec.kind == "attn":
         core, new_cache = attn.attn_decode(lp["core"], h, cache, pos, cfg=cfg,
@@ -268,40 +275,97 @@ def _layer_decode(lp, x, cache, pos, *, cfg, spec, window, runtime):
         h2 = apply_norm(lp["xnorm"], x, cfg.norm, cfg.norm_eps)
         x = x + attn.cross_attn_forward(lp["core"], h2, cache["xk"], cache["xv"],
                                         cfg=cfg)
-    if spec.ffn == "dense" and cfg.d_ff > 0:
+    if spec.ffn == "dense" and cfg.ffn_width(spec) > 0:
         h3 = apply_norm(lp["norm2"], x, cfg.norm, cfg.norm_eps)
         y, _ = apply_mlp(lp["ffn"], h3, cfg.act, jnp.dtype(cfg.compute_dtype))
         x = x + y
     elif spec.ffn == "moe":
         h3 = apply_norm(lp["norm2"], x, cfg.norm, cfg.norm_eps)
-        y, maux = moe_mod.moe_forward(lp["ffn"], h3, cfg=cfg)
-        aux = aux + maux["moe_aux"]
+        y, stats = moe_mod.moe_serve(lp["ffn"], h3, cfg=cfg, runtime=runtime)
         x = x + y
-    return x, new_cache, aux
+    return x, new_cache, stats
+
+
+_HELD_EXPERTS = ("we_gate", "we_up", "we_down")
+
+
+def _hoist_experts(stage_params, pattern):
+    """(the stage's weights without its serving expert layers' experts,
+    those experts still stacked over the stage's layers).  A layer scan
+    slices its xs per layer, and a kernel's operand sliced so is a copy:
+    the experts stay out of the xs and the grouped matmul picks its layer
+    from the stack (``_with_experts``)."""
+    xs, stacks = dict(stage_params), {}
+    for j, spec in enumerate(pattern):
+        if spec.ffn == "moe":
+            ffn = dict(xs[f"l{j}"]["ffn"])
+            stacks[f"l{j}"] = {k: ffn.pop(k) for k in _HELD_EXPERTS}
+            xs[f"l{j}"] = dict(xs[f"l{j}"], ffn=ffn)
+    return xs, stacks
+
+
+def _with_experts(pp, stacks, layer):
+    """One layer's weights ``pp`` with the stacked experts and the index of
+    the layer (``moe.moe_serve``'s ``"layer"``) put back."""
+    pp = dict(pp)
+    for key, st in stacks.items():
+        pp[key] = dict(pp[key], ffn=dict(pp[key]["ffn"], **st, layer=layer))
+    return pp
 
 
 def _stage_forward(stage_params, x, *, cfg, pattern, positions, seq_len,
                    runtime, enc_out, collect_cache, mode):
     windows = [resolve_window(cfg, spec, seq_len) for spec in pattern]
+    stacks = {}
+    if mode != "train":
+        stage_params, stacks = _hoist_experts(stage_params, pattern)
 
     def body(carry, pp):
-        x, aux = carry
-        caches = {}
+        x, aux, layer = carry if stacks else (*carry, None)
+        if stacks:
+            pp = _with_experts(pp, stacks, layer)
+        caches, stats = {}, {}
         for j, spec in enumerate(pattern):
-            x, c, a = _layer_forward(pp[f"l{j}"], x, cfg=cfg, spec=spec,
-                                     positions=positions, window=windows[j],
-                                     runtime=runtime, enc_out=enc_out,
-                                     mode=mode)
+            x, c, a, st = _layer_forward(pp[f"l{j}"], x, cfg=cfg, spec=spec,
+                                         positions=positions,
+                                         window=windows[j], runtime=runtime,
+                                         enc_out=enc_out, mode=mode)
             x = _shard_batch(x, runtime)
             caches[f"l{j}"] = c if collect_cache else {}
+            stats[f"l{j}"] = st
             aux = aux + a
-        return (x, aux), caches
+        carry = (x, aux, layer + 1) if stacks else (x, aux)
+        return carry, (caches, stats)
 
     if runtime.remat and mode == "train":
         body = jax.checkpoint(body)
-    (x, aux), caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), stage_params)
-    return x, aux, caches
+    init = (x, jnp.zeros((), jnp.float32))
+    if stacks:
+        init += (jnp.int32(0),)
+    (x, aux, *_), (caches, stats) = jax.lax.scan(body, init, stage_params)
+    return x, aux, caches, stats
+
+
+MOE_STATS = ("moe_routed_rows", "moe_experts_hit", "moe_held")
+
+
+def _layer_stats(stage_stats) -> dict:
+    """Per-layer serving stats in execution order: each of
+    :data:`MOE_STATS` the layers give, stacked over the expert layers on
+    a leading axis; nothing for a model without them.  ``stage_stats``: per stage, the
+    scan's stacked ``{"l<j>": stats}``."""
+    out = {}
+    for key in MOE_STATS:
+        parts = []
+        for st in stage_stats:
+            cols = [st[f"l{j}"][key] for j in range(len(st))
+                    if key in st[f"l{j}"]]
+            if cols:
+                parts.append(jnp.stack(cols, axis=1).reshape(
+                    (-1,) + cols[0].shape[1:]))
+        if parts:
+            out[key] = jnp.concatenate(parts)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -326,8 +390,8 @@ def _encoder_forward(params, enc_embed, cfg: ArchConfig, runtime):
     x = enc_embed.astype(jnp.dtype(cfg.compute_dtype))
 
     def body(x, lp):
-        x, _, _ = _layer_forward(lp, x, cfg=cfg, spec=spec, positions=pos,
-                                 window=-1, runtime=runtime, causal=False)
+        x, _, _, _ = _layer_forward(lp, x, cfg=cfg, spec=spec, positions=pos,
+                                    window=-1, runtime=runtime, causal=False)
         return x, None
 
     x, _ = jax.lax.scan(body, x, params["encoder"]["layers"])
@@ -353,18 +417,19 @@ def forward_hidden(params, batch, cfg: ArchConfig, runtime: Runtime = DEFAULT,
         enc_out = _shard_batch(enc_out, runtime)
 
     aux_total = jnp.zeros((), jnp.float32)
-    caches = []
+    caches, stats = [], []
     for si, stage in enumerate(cfg.stages):
-        x, aux, cache = _stage_forward(
+        x, aux, cache, st = _stage_forward(
             params["stages"][si], x, cfg=cfg, pattern=stage.pattern,
             positions=positions, seq_len=S, runtime=runtime, enc_out=enc_out,
             collect_cache=collect_cache, mode=mode)
         x = _shard_batch(x, runtime)
         aux_total = aux_total + aux
         caches.append(cache)
+        stats.append(st)
 
     x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
-    aux = {"moe_aux": aux_total}
+    aux = {"moe_aux": aux_total, **_layer_stats(stats)}
     if runtime.want_signature:
         from repro.kernels import ops as kops
         aux["signature"] = kops.signature(
@@ -465,7 +530,9 @@ def decode_step(params, token, caches, pos, cfg: ArchConfig,
                 runtime: Runtime = DEFAULT):
     """One decode step. token (B,1) int32, pos scalar int32.
 
-    Returns (logits (B,V) f32, new caches).
+    Returns (logits (B,V) f32, new caches, stats): ``stats`` holds the
+    serving expert layers' :data:`MOE_STATS` as ``forward_hidden``'s aux
+    does, and nothing for a model without them.
     """
     B = token.shape[0]
     compute = jnp.dtype(cfg.compute_dtype)
@@ -473,28 +540,37 @@ def decode_step(params, token, caches, pos, cfg: ArchConfig,
         if cfg.norm == "rmsnorm" and cfg.tie_embeddings else 1.0)
     x = _shard_batch(x, runtime)
     # decode window must match the shape the cache was built for
-    new_caches = []
+    new_caches, stage_stats = [], []
     for si, stage in enumerate(cfg.stages):
         pattern = stage.pattern
         cache_seq = _cache_seq_len(caches[si], pattern, cfg)
         windows = [resolve_window(cfg, spec, cache_seq) for spec in pattern]
 
-        def body(x, xs):
-            pp, cache = xs
-            new_cache = {}
-            for j, spec in enumerate(pattern):
-                xx, c, _ = _layer_decode(pp[f"l{j}"], x, cache[f"l{j}"], pos,
-                                         cfg=cfg, spec=spec, window=windows[j],
-                                         runtime=runtime)
-                new_cache[f"l{j}"] = c
-                x = xx
-            return x, new_cache
+        xs_params, stacks = _hoist_experts(params["stages"][si], pattern)
 
-        x, nc = jax.lax.scan(body, x, (params["stages"][si], caches[si]))
+        def body(carry, xs):
+            x, layer = carry if stacks else (carry, None)
+            pp, cache = xs
+            if stacks:
+                pp = _with_experts(pp, stacks, layer)
+            new_cache, stats = {}, {}
+            for j, spec in enumerate(pattern):
+                xx, c, st = _layer_decode(pp[f"l{j}"], x, cache[f"l{j}"],
+                                          pos, cfg=cfg, spec=spec,
+                                          window=windows[j], runtime=runtime)
+                new_cache[f"l{j}"] = c
+                stats[f"l{j}"] = st
+                x = xx
+            return ((x, layer + 1) if stacks else x), (new_cache, stats)
+
+        init = (x, jnp.int32(0)) if stacks else x
+        carry, (nc, st) = jax.lax.scan(body, init, (xs_params, caches[si]))
+        x = carry[0] if stacks else carry
         new_caches.append(nc)
+        stage_stats.append(st)
     x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits = unembed(params["embed"], x, compute, cfg.final_softcap)
-    return logits[:, 0], new_caches
+    return logits[:, 0], new_caches, _layer_stats(stage_stats)
 
 
 def _cache_seq_len(stage_cache, pattern, cfg) -> int:

@@ -100,19 +100,25 @@ def make_train_step(cfg: ArchConfig, optimizer: Optional[Optimizer] = None,
 
 
 def make_serve_prefill(cfg: ArchConfig, runtime: Runtime = Runtime()):
+    """``serve_prefill(params, batch) -> (last_logits, caches, stats)``;
+    ``stats`` holds the expert layers' per-layer counts
+    (``tfm.MOE_STATS``), and nothing for a model without them."""
     def serve_prefill(params, batch):
-        last_logits, caches, _ = tfm.prefill(params, batch, cfg, runtime)
-        return last_logits, caches
+        last_logits, caches, aux = tfm.prefill(params, batch, cfg, runtime)
+        return last_logits, caches, {k: aux[k] for k in tfm.MOE_STATS
+                                     if k in aux}
 
     return serve_prefill
 
 
 def make_serve_decode(cfg: ArchConfig, runtime: Runtime = Runtime()):
+    """``serve_decode(params, token, caches, pos) -> (next_token, logits,
+    caches, stats)``, ``stats`` as :func:`make_serve_prefill`'s."""
     def serve_decode(params, token, caches, pos):
-        logits, new_caches = tfm.decode_step(params, token, caches, pos, cfg,
-                                             runtime)
+        logits, new_caches, stats = tfm.decode_step(params, token, caches,
+                                                    pos, cfg, runtime)
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return next_token, logits, new_caches
+        return next_token, logits, new_caches, stats
 
     return serve_decode
 

@@ -38,8 +38,8 @@ def test_decode_matches_full_forward(arch):
     bp["tokens"] = toks[:, :S - 1]
     _, caches, _ = T.prefill(params, bp, cfg)
     caches = _pad_caches(caches, cfg)
-    logits_dec, new_caches = T.decode_step(params, toks[:, S - 1:S], caches,
-                                           jnp.int32(S - 1), cfg)
+    logits_dec, new_caches, _ = T.decode_step(
+        params, toks[:, S - 1:S], caches, jnp.int32(S - 1), cfg)
     diff = float(jnp.max(jnp.abs(logits_dec - logits_full[:, -1])))
     assert diff < 2e-2, f"{arch}: decode diverges from full forward ({diff})"
     # cache structure is preserved
@@ -66,7 +66,7 @@ def test_multi_step_decode_tracks_full_forward(arch):
     caches = _pad_caches(caches, cfg, extra=n_new)
     for step in range(n_new):
         pos = S_prompt + step
-        logits_dec, caches = T.decode_step(
+        logits_dec, caches, _ = T.decode_step(
             params, toks[:, pos:pos + 1], caches, jnp.int32(pos), cfg)
         diff = float(jnp.max(jnp.abs(logits_dec - logits_full[:, pos])))
         assert diff < 2e-2, f"{arch} step {step}: {diff}"
